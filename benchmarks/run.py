@@ -11,7 +11,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write results as JSON (e.g. BENCH_kernels.json;"
@@ -25,6 +25,7 @@ def main() -> None:
 
     only = [s for s in (args.only or "").split(",") if s]
     grouped: dict[str, list] = {}
+    failed: list[str] = []
     print("name,us_per_call,derived")
     for bench in ALL_BENCHES:
         if only and not any(s in bench.__name__ for s in only):
@@ -40,6 +41,7 @@ def main() -> None:
                             "us_per_call": 0.0,
                             "derived": f"ERROR:{type(e).__name__}:{e}",
                             "error": f"{type(e).__name__}: {e}"})
+            failed.append(bench.__name__)
             continue
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
@@ -56,7 +58,12 @@ def main() -> None:
                 json.dump({"schema": "bench-rows/v1", "rows": results}, f,
                           indent=1)
             print(f"# wrote {len(results)} rows to {path}", file=sys.stderr)
+    if failed:
+        print(f"# {len(failed)} bench(es) raised: {', '.join(failed)}",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -23,3 +23,8 @@ Attention); the flash kernel is therefore the paper-faithful artifact, and
 groupnorm_silu / conv2d are beyond-paper additions targeting the post-FA
 bottleneck the paper identifies.
 """
+
+# Scoped VMEM limit the kernels are compiled with.  One v5e TensorCore has
+# 128 MiB of VMEM; Mosaic's default scope is 16 MiB, too small for
+# full-width diffusion tiles.  The margin is Mosaic's own scratch.
+VMEM_LIMIT = 100 * 2**20

@@ -177,7 +177,7 @@ def conv2d(
     residual: jax.Array | None = None,  # (B, OH, OW, C_out)
     emit_stats: bool = False,
     impl: Impl = "auto",
-    block_rows: int = 2048,
+    block_rows: int | None = None,
     block_cin: int = 256,
     block_cout: int = 256,
 ):
@@ -232,9 +232,14 @@ def conv2d(
         )
 
     if impl in ("pallas", "interpret"):
+        from repro.parallel.sharding import kernel_on_mesh
+
         static = (stride, gn_silu, silu, emit_stats, impl == "interpret",
                   block_rows, block_cin, block_cout)
-        return _conv2d_fused(static, x, w, gn_a, gn_b, bias, temb, residual)
+        return kernel_on_mesh(
+            functools.partial(_conv2d_fused, static),
+            (x, w, gn_a, gn_b, bias, temb, residual),
+            (True, False, True, True, False, True, True))
 
     raise ValueError(f"unknown impl {impl!r}")
 
@@ -283,10 +288,14 @@ def temporal_conv1d(
     B, F, H, W, C = x.shape
     impl = _resolve(impl)
     if impl in ("pallas", "interpret"):
+        from repro.parallel.sharding import kernel_on_mesh
+
         N = H * W
         # divisor-based blocking: the (B,F,N,C) view is tiled in place with
         # no padded HBM copy (the whole point of the fused layout)
         bn = _largest_divisor(N, block_n)
-        y = _tconv_fused((bn, impl == "interpret"), x.reshape(B, F, N, C), w, bias)
+        y = kernel_on_mesh(
+            functools.partial(_tconv_fused, (bn, impl == "interpret")),
+            (x.reshape(B, F, N, C), w, bias), (True, False, False))
         return y.reshape(B, F, H, W, w.shape[-1])
     return _ref.temporal_conv1d_ref(x, w, bias)

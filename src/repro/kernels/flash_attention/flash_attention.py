@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_LIMIT
+
 NEG_INF = -1e30
 # Lane width of the VPU; scalar-per-row scratch is stored broadcast over one
 # 128-lane vector so it maps onto native VREG tiles.
@@ -187,27 +189,37 @@ def flash_attention_bhsd(
 # ---------------------------------------------------------------------------
 
 
-def _temporal_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, frames_valid: int):
-    # Blocks arrive as (1, F, HWB, 1, D): frames x spatial-block x head-dim.
-    q = q_ref[0, :, :, 0, :].astype(jnp.float32)  # (F, N, D)
-    k = k_ref[0, :, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, :, 0, :].astype(jnp.float32)
-    F = q.shape[0]
+def _temporal_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
+                     frames_valid: int, heads: int, head_dim: int):
+    # Blocks arrive as (1, F, HWB, heads * head_dim): frames x spatial block
+    # x the lanes of ``heads`` heads, straight from the (B, F, HW, H*D)
+    # view of the UNet's spatial layout.
+    q, k, v = (jnp.swapaxes(r[0].astype(jnp.float32), 0, 1)
+               for r in (q_ref, k_ref, v_ref))  # (N, F, L)
+    F = q.shape[1]
 
     # Batched over the spatial axis N: each spatial position attends across
-    # frames.  On real TPU this lowers to a batched (F x D) @ (D x F) MXU op
-    # per spatial lane — tiny matmul dims (F ~ 8..64) with large batch, which
-    # is exactly the low-utilization regime the paper measures on GPU.  The
-    # fused index_map means the (B,F,HW,H,D) tensor is *never* permuted in HBM.
-    s = jnp.einsum("fnd,gnd->nfg", q, k, preferred_element_type=jnp.float32) * scale
-    if frames_valid < F:
-        g = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(g < frames_valid, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("nfg,gnd->fnd", p, v, preferred_element_type=jnp.float32)
-    o_ref[0, :, :, 0, :] = out.astype(o_ref.dtype)
+    # frames — an (F x D) @ (D x F) MXU op per spatial lane, tiny matmul
+    # dims (F ~ 8..64) with a large batch, which is exactly the
+    # low-utilization regime the paper measures on GPU.  The fused
+    # index_map means the (B,F,HW,H,D) tensor is *never* permuted in HBM.
+    # Heads share the lane axis; head h is selected with a lane mask (a
+    # lane slice at an offset that is not a multiple of 128 does not lower).
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, 1, q.shape[2]), 2) // head_dim
+    out = jnp.zeros_like(q)
+    for h in range(heads):
+        mh = head == h
+        s = jnp.einsum("nfl,ngl->nfg", jnp.where(mh, q, 0.0), k,
+                       preferred_element_type=jnp.float32) * scale
+        if frames_valid < F:
+            g = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            s = jnp.where(g < frames_valid, s, NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        out = out + jnp.einsum("nfg,ngl->nfl", p, jnp.where(mh, v, 0.0),
+                               preferred_element_type=jnp.float32)
+    o_ref[0] = jnp.swapaxes(out, 0, 1).astype(o_ref.dtype)
 
 
 def temporal_flash_attention(
@@ -225,18 +237,25 @@ def temporal_flash_attention(
     assert HW % block_hw == 0, (HW, block_hw)
     n_hw = HW // block_hw
     frames_valid = F if frames_valid is None else frames_valid
+    # Heads per block: the fewest whose lanes fill whole 128-lane vregs,
+    # else all of them (the full lane dim).
+    hb = next((n for n in range(1, H + 1)
+               if H % n == 0 and (n * D) % 128 == 0), H)
 
     kernel = functools.partial(
-        _temporal_kernel, scale=scale, frames_valid=frames_valid
+        _temporal_kernel, scale=scale, frames_valid=frames_valid, heads=hb,
+        head_dim=D,
     )
-    spec = pl.BlockSpec(
-        (1, F, block_hw, 1, D), lambda b, h, ihw: (b, 0, ihw, h, 0)
-    )
-    return pl.pallas_call(
+    spec = pl.BlockSpec((1, F, block_hw, hb * D),
+                        lambda b, h, ihw: (b, 0, ihw, h))
+    flat = lambda t: t.reshape(B, F, HW, H * D)
+    out = pl.pallas_call(
         kernel,
-        grid=(B, H, n_hw),
+        grid=(B, H // hb, n_hw),
         in_specs=[spec, spec, spec],
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((B, F, HW, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, F, HW, H * D), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(q, k, v)
+    )(flat(q), flat(k), flat(v))
+    return out.reshape(B, F, HW, H, D)

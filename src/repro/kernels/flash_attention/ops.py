@@ -95,14 +95,17 @@ def attention(
         qt = _pad_to(q.transpose(0, 2, 1, 3), 2, min(block_q, _round_block(Sq)))
         kt = _pad_to(k.transpose(0, 2, 1, 3), 2, min(block_kv, _round_block(Skv)))
         vt = _pad_to(v.transpose(0, 2, 1, 3), 2, min(block_kv, _round_block(Skv)))
-        out = flash_attention_bhsd(
-            qt, kt, vt,
-            scale=scale, causal=causal, window=window,
-            sq_valid=Sq, skv_valid=Skv, kv_offset=kv_offset,
-            block_q=min(block_q, qt.shape[2]),
-            block_kv=min(block_kv, kt.shape[2]),
-            interpret=(impl == "interpret"),
-        )
+        from repro.parallel.sharding import kernel_on_mesh
+
+        out = kernel_on_mesh(
+            functools.partial(
+                flash_attention_bhsd,
+                scale=scale, causal=causal, window=window,
+                sq_valid=Sq, skv_valid=Skv, kv_offset=kv_offset,
+                block_q=min(block_q, qt.shape[2]),
+                block_kv=min(block_kv, kt.shape[2]),
+                interpret=(impl == "interpret")),
+            (qt, kt, vt), (True, True, True))
         return out[:, :, :Sq, :].transpose(0, 2, 1, 3)
     raise ValueError(f"unknown impl {impl!r}")
 
@@ -270,11 +273,14 @@ def temporal_attention(
         if hw_pad:
             pads = [(0, 0), (0, 0), (0, hw_pad), (0, 0), (0, 0)]
             x_q, x_k, x_v = (jnp.pad(t, pads) for t in (x_q, x_k, x_v))
-        out = temporal_flash_attention(
-            x_q, x_k, x_v, scale=scale,
-            block_hw=min(block_hw, x_q.shape[2]),
-            interpret=(impl == "interpret"),
-        )
+        from repro.parallel.sharding import kernel_on_mesh
+
+        out = kernel_on_mesh(
+            functools.partial(
+                temporal_flash_attention, scale=scale,
+                block_hw=min(block_hw, x_q.shape[2]),
+                interpret=(impl == "interpret")),
+            (x_q, x_k, x_v), (True, True, True))
         return out[:, :, :HW]
     # Conventional path: materialized permute, then standard attention over F.
     perm = lambda t: t.transpose(0, 2, 1, 3, 4).reshape(B * HW, F, H, D)

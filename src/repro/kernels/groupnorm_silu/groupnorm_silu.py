@@ -8,10 +8,11 @@ does one read + one write per element.
 
 Tiling: the diffusion hot shapes are latents — (B, N=H*W <= 64*64, C <= 1280)
 — so a whole (N, C) slab fits VMEM (64*64*1280*4B = 20 MB is too big in fp32;
-we therefore tile N and use a two-phase grid: phase 0 accumulates per-group
-sum/sum-of-squares into VMEM scratch, phase 1 re-streams the tile,
-normalizes, applies scale/bias + SiLU and writes.  2 reads + 1 write — still
-one fewer round trip than unfused, and no materialized intermediate).
+we therefore tile N and use a two-phase grid: phase 0 accumulates
+per-channel sum/sum-of-squares into VMEM scratch, phase 1 folds them into
+group statistics, re-streams the tile, normalizes, applies scale/bias +
+SiLU and writes.  2 reads + 1 write — still one fewer round trip than
+unfused, and no materialized intermediate).
 Grid = (B, 2, n_tiles); the phase axis exploits Pallas TPU's sequential grid.
 """
 
@@ -24,7 +25,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128
+
+def _group_matrix(C: int, groups: int) -> jax.Array:
+    """(C, groups) one-hot channel -> group membership."""
+    c = jax.lax.broadcasted_iota(jnp.int32, (C, groups), 0)
+    g = jax.lax.broadcasted_iota(jnp.int32, (C, groups), 1)
+    return (c // (C // groups) == g).astype(jnp.float32)
+
+
+def _matmul(a, b, contract_b: int) -> jax.Array:
+    """``a @ b`` (contract_b=0) or ``a @ b.T`` (contract_b=1), exact in fp32."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (contract_b,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _gn_kernel(
@@ -40,7 +54,6 @@ def _gn_kernel(
     silu: bool,
     n_valid: int,
     block_n: int,
-    n_tiles: int,
 ):
     phase = pl.program_id(1)
     it = pl.program_id(2)
@@ -52,30 +65,29 @@ def _gn_kernel(
 
     x = x_ref[0].astype(jnp.float32)  # (block_n, C)
     C = x.shape[1]
-    cpg = C // groups
     rows = it * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n, C), 0)
-    valid = rows < n_valid
-    xm = jnp.where(valid, x, 0.0)
+    xm = jnp.where(rows < n_valid, x, 0.0)
 
     @pl.when(phase == 0)
     def _accumulate():
-        xg = xm.reshape(block_n, groups, cpg)
-        # Per-group partial sums, broadcast over lanes for VREG-friendly scratch.
-        s = jnp.sum(xg, axis=(0, 2))  # (groups,)
-        s2 = jnp.sum(xg * xg, axis=(0, 2))
-        sum_scr[...] += jnp.broadcast_to(s[:, None], sum_scr.shape)
-        sq_scr[...] += jnp.broadcast_to(s2[:, None], sq_scr.shape)
+        # Per-channel partial sums; channels are folded into groups only
+        # once, in phase 1 (a lane-splitting reshape does not lower).
+        sum_scr[...] += jnp.sum(xm, axis=0, keepdims=True)
+        sq_scr[...] += jnp.sum(xm * xm, axis=0, keepdims=True)
 
     @pl.when(phase == 1)
     def _normalize():
-        count = n_valid * cpg
-        mean = sum_scr[:, :1] / count  # (groups, 1)
-        var = sq_scr[:, :1] / count - mean * mean
+        # channel sums -> group sums -> back to channels, as two exact
+        # one-hot matmuls over the (C, groups) membership matrix
+        m = _group_matrix(C, groups)
+        count = n_valid * (C // groups)
+        mean = _matmul(sum_scr[...], m, 0) / count  # (1, groups)
+        var = _matmul(sq_scr[...], m, 0) / count - mean * mean
         rstd = jax.lax.rsqrt(var + eps)
-        mean_c = jnp.repeat(mean, cpg, axis=0).reshape(1, C)
-        rstd_c = jnp.repeat(rstd, cpg, axis=0).reshape(1, C)
+        mean_c = _matmul(mean, m, 1)  # (1, C)
+        rstd_c = _matmul(rstd, m, 1)
         y = (x - mean_c) * rstd_c
-        y = y * scale_ref[0].astype(jnp.float32) + bias_ref[0].astype(jnp.float32)
+        y = y * scale_ref[...].astype(jnp.float32) + bias_ref[...].astype(jnp.float32)
         if silu:
             y = y * jax.nn.sigmoid(y)
         o_ref[0] = y.astype(o_ref.dtype)
@@ -106,7 +118,6 @@ def groupnorm_silu_pallas(
         silu=silu,
         n_valid=n_valid,
         block_n=block_n,
-        n_tiles=n_tiles,
     )
     return pl.pallas_call(
         kernel,
@@ -119,8 +130,8 @@ def groupnorm_silu_pallas(
         out_specs=pl.BlockSpec((1, block_n, C), lambda b, p, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, C), x.dtype),
         scratch_shapes=[
-            pltpu.VMEM((groups, _LANES), jnp.float32),
-            pltpu.VMEM((groups, _LANES), jnp.float32),
+            pltpu.VMEM((1, C), jnp.float32),
+            pltpu.VMEM((1, C), jnp.float32),
         ],
         interpret=interpret,
     )(x, scale[None], bias[None])

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
@@ -11,6 +12,30 @@ from repro.kernels.groupnorm_silu import ref as _ref
 from repro.kernels.groupnorm_silu.groupnorm_silu import groupnorm_silu_pallas
 
 Impl = Literal["auto", "pallas", "interpret", "jax"]
+
+# model-level impl (attention tier names) -> GroupNorm tier; the fallback
+# tiers of the other kernel packages all land on the jnp reference.
+_MODEL_IMPL = {
+    "auto": "auto",
+    "pallas": "pallas",
+    "interpret": "interpret",
+    "blocked_jax": "jax",
+    "xla": "jax",
+    "naive": "jax",
+    "jax": "jax",
+}
+
+
+def resolve_model_impl(impl: str | None) -> str:
+    """Model-level tier -> concrete GroupNorm tier (``auto`` resolved per
+    backend: pallas on TPU, jax elsewhere)."""
+    key = impl or "auto"
+    if key not in _MODEL_IMPL:
+        raise ValueError(f"unknown impl {impl!r} (expected one of {sorted(_MODEL_IMPL)})")
+    tier = _MODEL_IMPL[key]
+    if tier == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "jax"
+    return tier
 
 
 def groupnorm_silu(
@@ -30,8 +55,7 @@ def groupnorm_silu(
         x = x.reshape(B, H * W, C)
     B, N, C = x.shape
 
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "jax"
+    impl = resolve_model_impl(impl)
     if impl == "jax":
         out = _ref.groupnorm_silu_ref(x, scale, bias, groups=groups, eps=eps, silu=silu)
         return out.reshape(orig_shape)
@@ -40,9 +64,11 @@ def groupnorm_silu(
     pad = (-N) % bn
     if pad:
         x = jnp.pad(x, [(0, 0), (0, pad), (0, 0)])
-    out = groupnorm_silu_pallas(
-        x, scale, bias,
-        groups=groups, eps=eps, silu=silu, n_valid=N,
-        block_n=bn, interpret=(impl == "interpret"),
-    )
+    from repro.parallel.sharding import kernel_on_mesh
+
+    out = kernel_on_mesh(
+        functools.partial(
+            groupnorm_silu_pallas, groups=groups, eps=eps, silu=silu,
+            n_valid=N, block_n=bn, interpret=(impl == "interpret")),
+        (x, scale, bias), (True, False, False))
     return out[:, :N].reshape(orig_shape)

@@ -59,14 +59,21 @@ def make_production_mesh(*, multi_pod: bool = False):
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
-    """Tiny mesh over however many local devices exist (tests)."""
+    """(data, model) mesh over the first ``n_data * n_model`` local devices.
+
+    Axes are ``Auto``: the serving path places params and batches with
+    ``NamedSharding`` and lets the partitioner propagate the rest, which
+    ``jax.make_mesh``'s default ``Explicit`` axes reject (e.g. a gather
+    from a TP-sharded embedding table)."""
     import jax
 
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def mesh_chips(mesh) -> int:

@@ -34,6 +34,7 @@ import numpy as np
 import repro.configs.suite  # noqa: F401 — registers the paper suite
 from repro.configs import get_config, list_configs
 from repro.fleet import PLACEMENT_POLICIES, AutoscalePolicy, FleetRouter
+from repro.launch.cache import configure_compile_cache
 from repro.serving import PATTERNS, ArrivalTrace
 from repro.serving.engine import ServeConfig, ServeEngine
 from repro.telemetry import json_ready
@@ -199,6 +200,7 @@ def main():
                          "Chrome trace-event JSON (open in Perfetto; fleet "
                          "mode: one track per replica engine)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     mesh = None
     if args.mesh:

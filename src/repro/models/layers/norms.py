@@ -94,7 +94,6 @@ class GroupNorm(Module):
     groups: int = 32
     eps: float = 1e-5
     fuse_silu: bool = False
-    impl: str = "auto"  # auto | pallas | interpret | jax
     dtype: Any = jnp.float32
     name: str = "groupnorm"
 
@@ -104,10 +103,13 @@ class GroupNorm(Module):
             "bias": ParamDef((self.channels,), (None,), zeros_init, self.dtype),
         }
 
-    def __call__(self, params, x: jax.Array) -> jax.Array:
+    def __call__(self, params, x: jax.Array, *, impl: str = "auto") -> jax.Array:
+        """``impl`` is the caller's model-level tier (auto / naive /
+        blocked_jax / pallas / interpret), resolved by
+        ``groupnorm_silu.ops.resolve_model_impl``."""
         shape = x.shape
         x3 = x.reshape(shape[0], -1, shape[-1])
-        fused = self.impl in ("auto", "pallas", "interpret")
+        tier = gn_ops.resolve_model_impl(impl)
         out = gn_ops.groupnorm_silu(
             x3,
             params["scale"],
@@ -115,7 +117,8 @@ class GroupNorm(Module):
             groups=self.groups,
             eps=self.eps,
             silu=self.fuse_silu,
-            impl="jax" if self.impl == "auto" and jax.default_backend() != "tpu" else self.impl,
+            impl=tier,
         )
-        _record_norm(self.name, x, fused=fused, n_params=2 * self.channels)
+        _record_norm(self.name, x, fused=tier != "jax",
+                     n_params=2 * self.channels)
         return out.reshape(shape)

@@ -120,11 +120,11 @@ class ResBlock(Module):
             return self._conv2()(
                 params["conv2"], h, impl=impl, gn_affine=(a2, b2),
                 residual=skip)
-        h = self._gn1()(params["gn1"], x)
+        h = self._gn1()(params["gn1"], x, impl=impl)
         h = self._conv1()(params["conv1"], h, impl=impl)
         h = h + t[:, None, None, :].astype(h.dtype)
         _record_pointwise("temb_add", h)
-        h = self._gn2()(params["gn2"], h)
+        h = self._gn2()(params["gn2"], h, impl=impl)
         h = self._conv2()(params["conv2"], h, impl=impl)
         skip = x if self.c_in == self.c_out else self._skip()(params["skip"], x, impl=impl)
         _record_pointwise("residual_add", h, reads=2)
@@ -221,7 +221,7 @@ class SpatialTransformer(Module):
     def __call__(self, params, x, context=None, *, impl="auto"):
         B, H, W, C = x.shape
         res = x
-        h = self._gn()(params["gn"], x)
+        h = self._gn()(params["gn"], x, impl=impl)
         tokens = h.reshape(B, H * W, C)
         tokens = self._proj("proj_in")(params["proj_in"], tokens)
         ctx = None
@@ -432,5 +432,6 @@ class UNet2D(Module):
                 name="gn_out_stats")
             return conv_out(params["conv_out"], h, impl=impl, gn_affine=(a, b))
         h = GroupNorm(cfg.model_channels, min(cfg.groups, cfg.model_channels),
-                      fuse_silu=True, dtype=cfg.dtype)(params["gn_out"], h)
+                      fuse_silu=True, dtype=cfg.dtype)(params["gn_out"], h,
+                                                       impl=impl)
         return conv_out(params["conv_out"], h, impl=impl)

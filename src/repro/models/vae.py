@@ -94,7 +94,7 @@ class ConvDecoder(Module):
                     else:
                         h = GroupNorm(ci, min(self.cfg.groups, ci),
                                       fuse_silu=True, dtype=self.cfg.dtype)(
-                                          params["gn_out"], h)
+                                          params["gn_out"], h, impl=impl)
                         h = mod(params[name], h, impl=impl)
                 else:
                     h = mod(params[name], h, impl=impl)

@@ -220,6 +220,47 @@ def shard_report(params, specs_tree, mesh: Mesh, rules=None) -> dict:
     }
 
 
+def ambient_mesh() -> Mesh | None:
+    """The mesh of the enclosing ``with mesh:`` context, else None."""
+    from jax._src import mesh as mesh_lib
+
+    env_mesh = mesh_lib.thread_resources.env.physical_mesh
+    if env_mesh.empty:
+        env_mesh = mesh_lib.get_concrete_mesh()
+        if env_mesh is None or getattr(env_mesh, "empty", True):
+            return None
+    return env_mesh
+
+
+def kernel_on_mesh(fn, args: tuple, batched: tuple):
+    """``fn(*args)`` for a Pallas kernel call, run per device through
+    ``shard_map`` when an ambient mesh holds several devices: Mosaic kernels
+    cannot be partitioned automatically.
+
+    Args flagged in ``batched`` split their leading (batch) dim over the
+    mesh's batch axes when it divides; every other arg (weights, biases) is
+    whole on each device, so on the ``model`` axis each shard runs the
+    whole kernel.  Outputs are batch-major.  ``None`` args pass through."""
+    mesh = ambient_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return fn(*args)
+    first = next(a for a, b in zip(args, batched) if b and a is not None)
+    lead = batch_sharding_for(mesh, first.shape[0], 1).spec[0]
+    keep = [i for i, a in enumerate(args) if a is not None]
+
+    def body(*xs):
+        full = list(args)
+        for i, x in zip(keep, xs):
+            full[i] = x
+        return fn(*full)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=tuple(P(lead) if batched[i] else P() for i in keep),
+        out_specs=P(lead), check_vma=False,
+    )(*(args[i] for i in keep))
+
+
 def constrain(x, spec_names: tuple):
     """Activation sharding constraint using the ambient mesh context.
 
@@ -227,13 +268,9 @@ def constrain(x, spec_names: tuple):
     axis name, or None.  No-op outside a mesh context (unit tests) and for
     dims that don't divide their axis (long_500k batch=1 stays replicated).
     """
-    from jax._src import mesh as mesh_lib
-
-    env_mesh = mesh_lib.thread_resources.env.physical_mesh
-    if env_mesh.empty:
-        env_mesh = mesh_lib.get_concrete_mesh()
-        if env_mesh is None or getattr(env_mesh, "empty", True):
-            return x
+    env_mesh = ambient_mesh()
+    if env_mesh is None:
+        return x
     parts = []
     used: set = set()
     for dim, name in zip(x.shape, spec_names):

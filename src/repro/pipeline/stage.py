@@ -204,14 +204,17 @@ def stage_unit_cost(stage) -> float:
 
 
 def effective_tier(impl: str) -> str:
-    """Degrade the ``pallas`` tier to ``interpret`` off-TPU.
+    """The tier a stage actually runs, as the stats report it.
 
-    A per-stage override like ``stage_impl={"sr": "pallas"}`` names the
-    deployment kernel; on a CPU/GPU host the same kernel body runs in
-    interpret mode (the CI tier) instead of failing to lower.  All other
-    tiers pass through — ``auto`` keeps its backend-aware resolution inside
-    each kernel package."""
-    if impl == "pallas" and jax.default_backend() != "tpu":
+    ``auto`` resolves per backend: ``pallas`` on a TPU, the ``blocked_jax``
+    fallback elsewhere (what every kernel package's own ``auto`` picks
+    there).  A ``pallas`` request such as ``stage_impl={"sr": "pallas"}``
+    runs the same kernel body in interpret mode (the CI tier) off-TPU
+    instead of failing to lower.  All other tiers pass through."""
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "auto":
+        return "pallas" if on_tpu else "blocked_jax"
+    if impl == "pallas" and not on_tpu:
         return "interpret"
     return impl
 
@@ -222,9 +225,9 @@ class StageExecutor:
     Owns the stage's batch size (``max_batch``, derived from its mean HBM
     demand under the shared budget) and its kernel tier: ``impl`` is the
     tier requested for *this stage* (``ServeConfig.stage_impl`` override or
-    the engine-wide default), ``effective_impl`` what actually runs after
-    the off-TPU ``pallas -> interpret`` degrade.  Per-batch wall time and
-    batch-size samples feed the ``summary()`` tail-latency report.
+    the engine-wide default), ``effective_impl`` what actually runs
+    (:func:`effective_tier`).  Per-batch wall time and batch-size samples
+    feed the ``summary()`` tail-latency report.
 
     ``stage_index`` is the stage's position in the cost descriptor — what
     the suite-wide ``stage_key(seed, rid, stage_index)`` PRNG contract

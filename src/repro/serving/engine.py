@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.pipeline import CascadePipeline, resolve_stage_impls
+from repro.pipeline import CascadePipeline, effective_tier, resolve_stage_impls
 from repro.serving.scheduler import (
     BucketedScheduler,
     DenoisePodScheduler,
@@ -181,8 +181,11 @@ class ServeEngine:
         # validate per-stage tier overrides up front on EVERY route (a typo
         # must not silently serve the default tier); all routes execute the
         # same stage driver, so the overrides apply everywhere
-        resolve_stage_impls(self.cost.stages, serve_cfg.impl,
-                            serve_cfg.stage_impl)
+        self._stage_tiers = {
+            st.name: (im, effective_tier(im)) for st, im in zip(
+                self.cost.stages,
+                resolve_stage_impls(self.cost.stages, serve_cfg.impl,
+                                    serve_cfg.stage_impl))}
         self.stats: dict = {"schema": STATS_SCHEMA_VERSION,
                             "requests": 0, "impl": serve_cfg.impl,
                             "tier_throughput": {},
@@ -273,8 +276,10 @@ class ServeEngine:
         ``on_stage`` hook of ``GenerativeWorkload.generate``).  The cascade
         route's richer per-stage report lives in ``stats["cascade"]``; the
         legacy lm keys (``prefill_s``/``decode_s``) stay mirrored."""
+        impl, effective = self._stage_tiers[name]
         s = self.stats["stages"].setdefault(
-            name, {"exec_s": 0.0, "items": 0, "dispatches": 0})
+            name, {"exec_s": 0.0, "items": 0, "dispatches": 0,
+                   "impl": impl, "effective_impl": effective})
         s["exec_s"] += wall_s
         s["items"] += batch
         s["dispatches"] += 1

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core import perf_model, tracer
+from repro.kernels.groupnorm_silu import ops as gn_ops
 from repro.models.layers.conv import TemporalConv1D
 from repro.models.unet import ResBlock, UNet2D, UNetConfig, Upsample
 
@@ -34,6 +35,22 @@ def test_resblock_fused_matches_unfused(resblock):
     y_ref = rb(p, x, temb, impl="blocked_jax")
     y_fused = rb(p, x, temb, impl="interpret")
     np.testing.assert_allclose(y_fused, y_ref, rtol=2e-4, atol=2e-4)
+
+
+def test_groupnorm_runs_the_callers_tier(resblock, monkeypatch):
+    """GroupNorm takes the tier its caller threads down: on a backend where
+    ``auto`` would pick the Pallas norm, ``impl="xla"`` reaches none."""
+    rb, p, x, temb = resblock
+    calls = []
+    real = gn_ops.groupnorm_silu_pallas
+    monkeypatch.setattr(gn_ops, "groupnorm_silu_pallas",
+                        lambda *a, **k: calls.append(k["interpret"])
+                        or real(*a, **k))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rb(p, x, temb, impl="xla")  # unfused ResBlock: gn1 and gn2 standalone
+    assert calls == []
+    rb._gn1()(p["gn1"], x, impl="interpret")
+    assert calls == [True]
 
 
 def test_resblock_fused_halves_hbm_traffic(resblock):

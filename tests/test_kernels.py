@@ -192,6 +192,21 @@ def test_conv2d_fused_epilogues(shape, combo):
             np.testing.assert_allclose(out, gold, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("stride", [1, 2])
+def test_conv2d_channel_blocks_match_oracle(stride):
+    """128-wide channel blocks: several C_in blocks reduce into one output
+    block and several C_out blocks share an input block (with stride 2 the
+    C_in blocks span both column phases)."""
+    x, w, s, ep = _conv_inputs((2, 9, 12, 256, 256, 3, stride), jnp.float32)
+    a, b = ep["gn_affine"]
+    kw = dict(stride=s, bias=ep["bias"], temb=ep["temb"], emit_stats=True)
+    gold = conv_ref.conv2d_ref(x, w, gn_a=a, gn_b=b, **kw)
+    out = conv_ops.conv2d(x, w, gn_affine=(a, b), impl="interpret",
+                          block_rows=24, block_cin=128, block_cout=128, **kw)
+    np.testing.assert_allclose(out[0], gold[0], rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out[1], gold[1], rtol=1e-4, atol=1e-3)
+
+
 def test_conv2d_grad_matches_xla():
     """The Pallas tiers define their backward pass through the xla ref."""
     x, w, s, ep = _conv_inputs(CONV_SHAPES[1], jnp.float32)

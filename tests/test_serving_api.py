@@ -163,3 +163,32 @@ def test_lm_serve_engine_backcompat_alias(rng_key):
     engine.submit(0, np.arange(5) % cfg.vocab, 3)
     out = engine.run()
     assert len(out[0]) == 3
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache sits at a fixed place: the
+    environment's ``JAX_COMPILATION_CACHE_DIR`` when set (JAX's own
+    setting, left alone), else ``.jax_cache/`` at the checkout root."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch.cache import configure_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = configure_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = Path(__file__).resolve().parents[1]  # the checkout
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

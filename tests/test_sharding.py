@@ -259,3 +259,40 @@ def test_mesh_stats_pass_schema_validation():
         eng.submit(rid, rng.integers(0, wl.prompt_vocab, size=8))
     eng.run()
     validate_engine_stats(eng.stats, eng.route)
+
+
+@needs_mesh
+@pytest.mark.parametrize("shape", [(8, 1), (4, 2)])
+def test_pallas_kernels_run_per_device_on_a_mesh(shape):
+    """Mosaic kernels are not partitioned automatically, so under a mesh
+    each Pallas call runs per device through ``shard_map``: batch split
+    over ``data``, a TP-sharded weight gathered whole.  The interpret tier
+    then matches its single-device result."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels.conv2d import ops as conv_ops
+    from repro.kernels.flash_attention import ops as fa_ops
+    from repro.kernels.groupnorm_silu import ops as gn_ops
+
+    key = jax.random.PRNGKey(3)
+    x = jax.random.normal(key, (8, 8, 8, 16))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 16, 16)) * 0.2
+    q = jax.random.normal(jax.random.fold_in(key, 2), (8, 64, 2, 8))
+
+    def f(x, w, q):
+        y, st = conv_ops.conv2d(x, w, emit_stats=True, impl="interpret")
+        g = gn_ops.groupnorm_silu(y, w[0, 0, 0], w[0, 0, 1], groups=4,
+                                  impl="interpret")
+        return g, st, fa_ops.attention(q, q, q, impl="interpret")
+
+    want = f(x, w, q)
+    mesh = make_debug_mesh(*shape)
+    xs = jax.device_put(x, NamedSharding(mesh, P("data")))
+    ws = jax.device_put(w, NamedSharding(mesh, P(None, None, None, "model")))
+    qs = jax.device_put(q, NamedSharding(mesh, P("data")))
+    with mesh:
+        assert str(jax.make_jaxpr(f)(xs, ws, qs)).count("shard_map") == 3
+        got = f(xs, ws, qs)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)

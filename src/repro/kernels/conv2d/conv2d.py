@@ -337,6 +337,7 @@ def conv2d_pallas(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bh * Wc, bcout), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        name="conv2d",
         interpret=interpret,
     )(*inputs)
     y = out[0][:, :OH, :OW]
@@ -397,5 +398,6 @@ def temporal_conv1d_pallas(
         out_specs=pl.BlockSpec((1, F, block_n, bcout), lambda b, co, i: (b, 0, i, co)),
         out_shape=jax.ShapeDtypeStruct((B, F, N, C_out), x.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        name="temporal_conv1d",
         interpret=interpret,
     )(x, w, bias.reshape(1, C_out))

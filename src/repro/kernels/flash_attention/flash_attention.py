@@ -179,6 +179,7 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, D), jnp.float32),  # acc
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 
@@ -256,6 +257,7 @@ def temporal_flash_attention(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, F, HW, H * D), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        name="temporal_attention",
         interpret=interpret,
     )(flat(q), flat(k), flat(v))
     return out.reshape(B, F, HW, H, D)

@@ -133,5 +133,6 @@ def groupnorm_silu_pallas(
             pltpu.VMEM((1, C), jnp.float32),
             pltpu.VMEM((1, C), jnp.float32),
         ],
+        name="groupnorm_silu",
         interpret=interpret,
     )(x, scale[None], bias[None])

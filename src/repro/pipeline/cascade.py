@@ -37,7 +37,7 @@ from repro.pipeline.stage import (
     state_nbytes,
     state_signature,
 )
-from repro.telemetry import SpanCollector
+from repro.telemetry import MetricsRegistry, SpanCollector
 
 # Modeled per-dispatch launch overhead, as a fraction of the mean stage unit
 # cost: what a stage-batch pays for compiled-graph dispatch regardless of
@@ -106,7 +106,9 @@ class CascadePipeline:
         self.workload = workload
         # lifecycle span sink — the owning engine passes its collector so
         # pipeline queue/exec/preempt spans land on the engine's timeline
-        self.spans = spans if spans is not None else SpanCollector("pipeline")
+        # and the stage spans count each dispatch into its registry
+        self.spans = spans if spans is not None else SpanCollector(
+            "pipeline", metrics=MetricsRegistry())
         self.params = params
         self.impl = impl
         self.pod_size = max(1, pod_size)
@@ -136,7 +138,7 @@ class CascadePipeline:
         self.executors = [
             StageExecutor(workload, s, impl=im, max_batch=b,
                           temperature=temperature, stage_index=i,
-                          mesh=self.stage_meshes[i])
+                          mesh=self.stage_meshes[i], spans=self.spans)
             for i, (s, b, im) in enumerate(zip(self.stages, batches, impls))
         ]
         # buffers[i] feeds stage i; buffers[0] is the (unbounded) admission
@@ -241,11 +243,8 @@ class CascadePipeline:
             for t in tasks:  # queue-wait slice: push tick -> this dispatch
                 self.spans.span("queue", cat="queue", start_tick=t.enqueued,
                                 end_tick=self.ticks, lane=name, rid=t.rid)
-            new_tasks = ex.run_batch(self.stage_params[i], tasks, self._key)
-            self.spans.span(name, cat="exec", start_tick=self.ticks,
-                            dur_ticks=1.0, dur_s=ex.last_service_s,
-                            lane=name, batch=len(tasks),
-                            impl=ex.effective_impl)
+            new_tasks = ex.run_batch(self.stage_params[i], tasks, self._key,
+                                     tick=self.ticks)
             executed += 1
             self.executed.append((i, len(tasks)))
             if out_buf is None:
@@ -390,8 +389,8 @@ class CascadePipeline:
                                   "items": 0, "exec_s": 0.0})
             t["requested"].add(ex.impl)
             t["stages"].append(ex.name)
-            t["items"] += ex.items
-            t["exec_s"] += ex.exec_s
+            t["items"] += s["items"]
+            t["exec_s"] += s["exec_s"]
         for t in tiers.values():
             t["requested"] = sorted(t["requested"])
             t["rps"] = (t["items"] / t["exec_s"]) if t["exec_s"] else 0.0

@@ -11,9 +11,9 @@ never popping more work than the downstream buffer has room for.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
-import time
 from collections import deque
 from typing import Any
 
@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.telemetry import Histogram
+from repro.telemetry import Histogram, SpanCollector
 
 
 # ---------------------------------------------------------------------------
@@ -187,6 +187,46 @@ class StageBuffer:
 
 
 # ---------------------------------------------------------------------------
+# One stage dispatch: the span both stage drivers open
+# ---------------------------------------------------------------------------
+
+_UNCOUNTED = SpanCollector("stages", enabled=False)
+
+
+@contextlib.contextmanager
+def stage_span(spans: SpanCollector | None, stage, *, batch: int, tier: str,
+               tick: float | None = None):
+    """One dispatch of ``stage`` over ``batch`` requests on kernel tier
+    ``tier``: the characterization tracer's scope named after the stage and
+    the ``serve/stage/<name>`` program span (wall-clock stamps, profiler
+    annotation, compile events charged to the stage).  With a registry on
+    ``spans`` the dispatch is counted there: ``stage_exec_s/<name>`` (host
+    seconds: dispatch plus any trace, lowering and compile, not device
+    time), ``stage_items/<name>`` and ``stage_dispatches/<name>``.  Yields
+    the open span; its ``seconds`` is set on exit."""
+    from repro.core import tracer
+
+    spans = _UNCOUNTED if spans is None else spans
+    with tracer.scope(stage.name), spans.region(
+            f"serve/stage/{stage.name}", cat="exec", lane=stage.name,
+            tick=tick, charge=stage.name, batch=batch, tier=tier) as span:
+        yield span
+    if spans.metrics is not None:
+        spans.metrics.counter(f"stage_exec_s/{stage.name}").inc(span.seconds)
+        spans.metrics.counter(f"stage_items/{stage.name}").inc(batch)
+        spans.metrics.counter(f"stage_dispatches/{stage.name}").inc()
+
+
+def stage_counts(counters: dict, name: str) -> dict:
+    """Stage ``name``'s dispatch counts as :func:`stage_span` keeps them,
+    from a registry's ``counters()``: ``exec_s`` (host seconds), ``items``
+    and ``dispatches``."""
+    return {"exec_s": counters.get(f"stage_exec_s/{name}", 0.0),
+            "items": int(counters.get(f"stage_items/{name}", 0)),
+            "dispatches": int(counters.get(f"stage_dispatches/{name}", 0))}
+
+
+# ---------------------------------------------------------------------------
 # Stage executor
 # ---------------------------------------------------------------------------
 
@@ -226,8 +266,9 @@ class StageExecutor:
     demand under the shared budget) and its kernel tier: ``impl`` is the
     tier requested for *this stage* (``ServeConfig.stage_impl`` override or
     the engine-wide default), ``effective_impl`` what actually runs
-    (:func:`effective_tier`).  Per-batch wall time and batch-size samples
-    feed the ``summary()`` tail-latency report.
+    (:func:`effective_tier`).  Each dispatch runs in a :func:`stage_span`
+    on ``spans`` (a collector with a registry), whose counters and the
+    per-batch service-time histogram feed ``summary()``.
 
     ``stage_index`` is the stage's position in the cost descriptor — what
     the suite-wide ``stage_key(seed, rid, stage_index)`` PRNG contract
@@ -236,7 +277,8 @@ class StageExecutor:
 
     def __init__(self, workload, stage, *, impl: str = "auto",
                  max_batch: int = 4, temperature: float = 0.0,
-                 stage_index: int = 0, mesh=None):
+                 stage_index: int = 0, mesh=None,
+                 spans: SpanCollector):
         self.workload = workload
         self.stage = stage
         self.stage_index = stage_index
@@ -245,48 +287,47 @@ class StageExecutor:
         self.max_batch = max_batch
         self.temperature = temperature
         self.mesh = mesh  # optional per-stage device slice (see cascade.py)
-        # -- stats ----------------------------------------------------------
-        self.batches = 0
-        self.items = 0
-        self.exec_s = 0.0
-        self.batch_sizes: list[int] = []
+        self.spans = spans  # where each dispatch is recorded and counted
         # per-batch wall time (streaming log-bucket histogram, ~2% rel. res.)
         self.service_s = Histogram(f"{stage.name}/service_s",
                                    lo=1e-7, hi=1e4, resolution=0.02,
                                    scale="log")
-        self.last_service_s = 0.0  # wall s of the most recent dispatch
 
     @property
     def name(self) -> str:
         return self.stage.name
 
-    def run_batch(self, params, tasks: list[StageTask], key) -> list[StageTask]:
+    @property
+    def batches(self) -> int:
+        """Dispatches so far (the stage span's counter)."""
+        return self._counts()["dispatches"]
+
+    def _counts(self) -> dict:
+        return stage_counts(self.spans.metrics.counters("stage_"), self.name)
+
+    def run_batch(self, params, tasks: list[StageTask], key,
+                  tick: float = 0) -> list[StageTask]:
         """Execute the stage over ``tasks`` as one batch; returns the tasks
         with their post-stage states.  ``key`` is the pipeline's base seed
         key — per-request keys are derived here via the shared
-        ``stage_key`` fold, and the dispatch runs under the same per-stage
-        tracer scope the ``generate`` driver emits."""
-        from repro.core import tracer
+        ``stage_key`` fold, and the dispatch runs under the same
+        :func:`stage_span` the ``generate`` driver opens (at pipeline tick
+        ``tick``).  The executor waits for the result inside the span, so
+        its seconds here include the device time."""
         from repro.workload.base import stage_keys
 
         batched = stack_states([t.state for t in tasks])
         keys = stage_keys(key, [t.rid for t in tasks], self.stage_index)
         # forwarded only when set, so mesh-free run_stage doubles keep working
         mesh_kw = {} if self.mesh is None else {"mesh": self.mesh}
-        t0 = time.perf_counter()
-        with tracer.scope(self.stage.name):
+        with stage_span(self.spans, self.stage, batch=len(tasks),
+                        tier=self.effective_impl, tick=tick) as span:
             new = self.workload.run_stage(params, self.stage, batched, keys,
                                           impl=self.effective_impl,
                                           temperature=self.temperature,
                                           **mesh_kw)
-        new = jax.block_until_ready(new)
-        dt = time.perf_counter() - t0
-        self.exec_s += dt
-        self.service_s.observe(dt)
-        self.last_service_s = dt
-        self.batches += 1
-        self.items += len(tasks)
-        self.batch_sizes.append(len(tasks))
+            new = jax.block_until_ready(new)
+        self.service_s.observe(span.seconds)
         states = split_state(new, len(tasks))
         return [dataclasses.replace(t, state=s)
                 for t, s in zip(tasks, states)]
@@ -294,16 +335,18 @@ class StageExecutor:
     def summary(self) -> dict:
         """Per-stage serving report: batch counts, tiers, throughput, and
         the p50/p95 per-batch service-time sample."""
+        c = self._counts()
+        items, batches, exec_s = c["items"], c["dispatches"], c["exec_s"]
         out = {
-            "batches": self.batches,
-            "items": self.items,
-            "exec_s": self.exec_s,
-            "mean_batch": (self.items / self.batches) if self.batches else 0.0,
+            "batches": batches,
+            "items": items,
+            "exec_s": exec_s,
+            "mean_batch": (items / batches) if batches else 0.0,
             "max_batch": self.max_batch,
             "impl": self.impl,
             "effective_impl": self.effective_impl,
             "service_s": self.service_s.summary(),
-            "throughput_rps": (self.items / self.exec_s) if self.exec_s else 0.0,
+            "throughput_rps": (items / exec_s) if exec_s else 0.0,
         }
         if self.mesh is not None:
             out["mesh"] = {"axes": dict(self.mesh.shape),

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.pipeline import CascadePipeline, effective_tier, resolve_stage_impls
+from repro.pipeline.stage import stage_counts
 from repro.serving.scheduler import (
     BucketedScheduler,
     DenoisePodScheduler,
@@ -67,6 +68,7 @@ from repro.telemetry import (
     STATS_SCHEMA_VERSION,
     MetricsRegistry,
     SpanCollector,
+    compiles,
     write_chrome_trace,
 )
 from repro.workload import GenerativeWorkload, workload_for
@@ -186,7 +188,7 @@ class ServeEngine:
                 self.cost.stages,
                 resolve_stage_impls(self.cost.stages, serve_cfg.impl,
                                     serve_cfg.stage_impl))}
-        self.stats: dict = {"schema": STATS_SCHEMA_VERSION,
+        self._stats: dict = {"schema": STATS_SCHEMA_VERSION,
                             "requests": 0, "impl": serve_cfg.impl,
                             "tier_throughput": {},
                             "stage_impl": dict(serve_cfg.stage_impl or {}),
@@ -195,7 +197,7 @@ class ServeEngine:
         # -- telemetry: typed metrics + lifecycle spans ----------------------
         self.metrics = MetricsRegistry()
         if self.mesh is not None:
-            self.stats["mesh"] = {
+            self._stats["mesh"] = {
                 "axes": {k: int(v) for k, v in self.mesh.shape.items()},
                 "devices": int(self.mesh.devices.size),
                 "params": self._mesh_report,
@@ -204,7 +206,9 @@ class ServeEngine:
                 "sharding_replication_fallbacks",
                 "param dims replicated by the divisibility fallback",
             ).inc(self._mesh_report["replication_fallbacks"])
-        self.spans = SpanCollector(track="engine")
+        # program spans count their compiles into this engine's registry
+        self.spans = SpanCollector(track="engine", metrics=self.metrics)
+        compiles.install()
         self._requests_c = self.metrics.counter(
             "requests_submitted", "requests accepted by submit()")
         self._completed_c = self.metrics.counter(
@@ -218,9 +222,13 @@ class ServeEngine:
         self._ready_pods: deque = deque()  # pod route: admitted, unserved
         self._seq = 0
         self._arrival_tick: dict[int, int] = {}
+        self._submit_s: dict[int, float] = {}  # perf_counter() at submit
         # arrival -> admission / completion waits, streamed at 1-tick buckets
         self._admission_waits = self.metrics.histogram(
             "admission_wait_ticks", "arrival -> pipeline admission")
+        self._admission_wait_s = self.metrics.histogram(
+            "admission_wait_s", "arrival -> pipeline admission, wall seconds",
+            lo=1e-7, hi=1e4, resolution=0.02, scale="log")
         self._e2e_ticks = self.metrics.histogram(
             "request_e2e_ticks", "arrival -> completion")
         self._completed = 0
@@ -248,46 +256,54 @@ class ServeEngine:
                 spans=self.spans,  # pipeline spans join the engine timeline
                 mesh=self.mesh,  # per-stage device slices (see cascade.py)
             )
-            self.stats.update(generate_s=0.0, pods=0, bandwidth_profile=[],
+            self._stats.update(generate_s=0.0, pods=0, bandwidth_profile=[],
                               cascade={})
         elif self.route == "lm":
             self.scheduler = BucketedScheduler(serve_cfg.buckets,
                                                serve_cfg.max_batch)
-            self.stats.update(prefill_s=0.0, decode_s=0.0, tokens=0,
+            self._stats.update(prefill_s=0.0, decode_s=0.0, tokens=0,
                               padding_waste=[])
         else:
             self.scheduler = DenoisePodScheduler(
                 pod_size=serve_cfg.resolved_pod_size,
                 total_steps=self.cost.iterative_steps(),
             )
-            self.stats.update(generate_s=0.0, pods=0, bandwidth_profile=[])
+            self._stats.update(generate_s=0.0, pods=0, bandwidth_profile=[])
 
     def _record_tier(self, n_done: int, wall_s: float) -> None:
         """Per-``impl``-tier served-request throughput; stage-level tier
         attribution lives in ``stats["cascade"]["tiers"]``."""
-        t = self.stats["tier_throughput"].setdefault(
+        t = self._stats["tier_throughput"].setdefault(
             self.serve_cfg.impl, {"requests": 0, "wall_s": 0.0, "rps": 0.0})
         t["requests"] += n_done
         t["wall_s"] += wall_s
         t["rps"] = t["requests"] / t["wall_s"] if t["wall_s"] else 0.0
 
-    def _record_stage(self, name: str, wall_s: float, batch: int) -> None:
-        """Per-stage time attribution for the driver-executed routes (the
-        ``on_stage`` hook of ``GenerativeWorkload.generate``).  The cascade
-        route's richer per-stage report lives in ``stats["cascade"]``; the
-        legacy lm keys (``prefill_s``/``decode_s``) stay mirrored."""
-        impl, effective = self._stage_tiers[name]
-        s = self.stats["stages"].setdefault(
-            name, {"exec_s": 0.0, "items": 0, "dispatches": 0,
-                   "impl": impl, "effective_impl": effective})
-        s["exec_s"] += wall_s
-        s["items"] += batch
-        s["dispatches"] += 1
-        self.spans.span(name, cat="exec", start_tick=self._tick,
-                        dur_ticks=1.0, dur_s=wall_s, lane=name, batch=batch)
-        legacy = {"prefill": "prefill_s", "decode": "decode_s"}
-        if name in legacy and legacy[name] in self.stats:
-            self.stats[legacy[name]] += wall_s
+    @property
+    def stats(self) -> dict:
+        """The engine's report (schema: ``repro.telemetry.schema``).  Its
+        ``stages`` block is built here, when read, from the counters the
+        stage spans keep (:func:`repro.pipeline.stage.stage_span`, one per
+        dispatch on every route): ``exec_s`` is host seconds in the stage —
+        dispatch plus any trace, lowering and compile, and on the cascade
+        route the wait for the result — not device time; ``compiles`` /
+        ``compile_s`` are the JAX compiles charged to the stage
+        (``repro.telemetry.compiles``).  The cascade route's richer
+        per-stage report lives in ``stats["cascade"]``; the legacy lm keys
+        (``prefill_s`` / ``decode_s``) mirror their stages' ``exec_s``."""
+        s = self._stats
+        c = self.metrics.counters()
+        for name, (impl, effective) in self._stage_tiers.items():
+            if f"stage_dispatches/{name}" in c:
+                s["stages"][name] = dict(
+                    stage_counts(c, name), impl=impl,
+                    effective_impl=effective,
+                    compiles=int(c.get(f"compiles/{name}", 0)),
+                    compile_s=c.get(f"compile_s/{name}", 0.0))
+        for name, key in (("prefill", "prefill_s"), ("decode", "decode_s")):
+            if key in s and name in s["stages"]:
+                s[key] = s["stages"][name]["exec_s"]
+        return s
 
     # -- submission ----------------------------------------------------------
 
@@ -339,13 +355,16 @@ class ServeEngine:
         else:
             self._seq += 1
             heapq.heappush(self._future, (int(arrival_tick), self._seq, sreq))
-        self.stats["requests"] += 1
+        self._stats["requests"] += 1
         self._requests_c.inc()
+        self._submit_s[req.rid] = time.perf_counter()
 
     def _enqueue(self, sreq: Request, tick: int) -> None:
         """Hand an arrived request to the route scheduler, stamped with its
-        arrival tick (what the admission-wait and e2e latencies key off)."""
+        arrival tick and wall time (what the admission-wait and e2e
+        latencies key off)."""
         sreq.arrived_at = float(tick)
+        sreq.arrived_s = time.perf_counter()
         self._arrival_tick[sreq.rid] = tick
         self.scheduler.submit(sreq)
 
@@ -398,20 +417,23 @@ class ServeEngine:
     def _record_pod_profile(self, pod: list) -> None:
         """Stagger schedule + §V-A bandwidth profile for one admitted pod."""
         schedule = self.scheduler.schedule(pod)
-        self.stats["bandwidth_profile"].append(
+        self._stats["bandwidth_profile"].append(
             DenoisePodScheduler.bandwidth_profile(
                 self.cost.step_demands(), schedule))
-        self.stats["pods"] += 1
+        self._stats["pods"] += 1
         for r in pod:
             self._record_admission(r)
 
     def _record_admission(self, r) -> None:
-        """Arrival -> scheduler-admission wait: histogram sample + span."""
-        arrived = int(r.arrived_at)
+        """Arrival -> scheduler-admission wait: histogram samples (ticks and
+        wall seconds) + span."""
+        arrived, now = int(r.arrived_at), time.perf_counter()
         self._admission_waits.observe(self._tick - arrived)
+        self._admission_wait_s.observe(now - r.arrived_s)
         self.spans.span("admission_wait", cat="admission",
                         start_tick=arrived, end_tick=self._tick,
-                        lane="admission", rid=r.rid)
+                        lane="admission", rid=r.rid, start_s=r.arrived_s,
+                        end_s=now)
 
     # -- LM route ------------------------------------------------------------
 
@@ -438,7 +460,7 @@ class ServeEngine:
             temperature=self.serve_cfg.temperature,
             max_new_tokens=[r.max_new_tokens for r in requests],
             rids=[r.rid for r in requests],
-            on_stage=self._record_stage, **mesh_kw)
+            spans=self.spans, **mesh_kw)
 
     def _step_lm(self) -> list[tuple[int, Any]]:
         """Serve one bucketed batch through the stage driver — the same
@@ -451,10 +473,10 @@ class ServeEngine:
             return []
         for r in batch:
             self._record_admission(r)
-        self.stats["padding_waste"].append(
+        self._stats["padding_waste"].append(
             self.scheduler.padding_waste(batch, bucket))
         outs = self._drive(batch, bucket)
-        self.stats["tokens"] += (
+        self._stats["tokens"] += (
             max(r.max_new_tokens for r in batch) * len(batch))
         self._record_tier(len(batch), time.perf_counter() - t_step)
         return [(r.rid, [int(t) for t in outs[i]])
@@ -468,17 +490,21 @@ class ServeEngine:
         pod = self._ready_pods.popleft() if self._ready_pods else []
         if not pod:
             return []
-        # staggered step indices for the pod (paper §V-A) + the resulting
-        # instantaneous-HBM-demand flattening vs the aligned baseline
-        self._record_pod_profile(pod)
+        with self.spans.region("serve/pod", cat="serve", lane="pod",
+                               batch=len(pod),
+                               pod_size=self.serve_cfg.resolved_pod_size):
+            # staggered step indices for the pod (paper §V-A) + the
+            # resulting instantaneous-HBM-demand flattening vs the aligned
+            # baseline
+            self._record_pod_profile(pod)
 
-        t0 = time.perf_counter()
-        outs = self._drive(pod, max(r.prompt_len for r in pod))
-        outs = [jax.block_until_ready(o) for o in outs]
-        dt = time.perf_counter() - t0
-        self.stats["generate_s"] += dt
-        self._record_tier(len(pod), dt)
-        return [(r.rid, np.asarray(outs[i])) for i, r in enumerate(pod)]
+            t0 = time.perf_counter()
+            outs = self._drive(pod, max(r.prompt_len for r in pod))
+            outs = [jax.block_until_ready(o) for o in outs]
+            dt = time.perf_counter() - t0
+            self._stats["generate_s"] += dt
+            self._record_tier(len(pod), dt)
+            return [(r.rid, np.asarray(outs[i])) for i, r in enumerate(pod)]
 
     # -- cascade route -------------------------------------------------------
 
@@ -504,7 +530,7 @@ class ServeEngine:
         t0 = time.perf_counter()
         done = self.pipeline.tick()
         dt = time.perf_counter() - t0
-        self.stats["generate_s"] += dt
+        self._stats["generate_s"] += dt
         self._record_tier(len(done), dt)
         return [(rid, np.asarray(out)) for rid, out in done]
 
@@ -544,13 +570,13 @@ class ServeEngine:
         """Refresh ``stats["cascade"]`` once the pipeline drains (summary
         walks the full dispatch/occupancy logs — O(ticks^2) if per-tick),
         folding in the engine-level admission/latency report."""
-        self.stats["cascade"] = self.pipeline.summary()
-        self.stats["cascade"]["admission"] = {
+        self._stats["cascade"] = self.pipeline.summary()
+        self._stats["cascade"]["admission"] = {
             "policy": self.serve_cfg.admission,
             "flush_wait_ticks": self.serve_cfg.arrival_flush_wait,
             "wait_ticks": self._admission_waits.summary(),
         }
-        self.stats["cascade"]["request_latency_ticks"] = (
+        self._stats["cascade"]["request_latency_ticks"] = (
             self._e2e_ticks.summary())
 
     # -- unified loop --------------------------------------------------------
@@ -559,31 +585,37 @@ class ServeEngine:
         """Advance the serving clock one tick: admit due arrivals, serve one
         scheduled batch / pod / pipeline round, release closed-loop
         requests for completions.  Returns completed ``(rid, out)`` pairs
-        (often empty mid-pipeline)."""
-        t0 = time.perf_counter()
-        self._admit_arrivals()
-        if self.route == "cascade":
-            n_exec = len(self.pipeline.executed)
-            done = self._step_cascade()
-            busy = len(self.pipeline.executed) > n_exec
-        elif self.route == "lm":
-            done = self._step_lm()
-            busy = bool(done)
-        else:
-            done = self._step_pod()
-            busy = bool(done)
-        if busy:  # tick->wall-clock calibration sample (busy ticks only)
-            self._busy_wall_s.observe(time.perf_counter() - t0)
-        self._completed += len(done)
-        self._completed_c.inc(len(done))
-        for rid, _ in done:
-            if rid in self._arrival_tick:
-                arrival = self._arrival_tick[rid]
-                self._e2e_ticks.observe(self._tick - arrival)
-                self.spans.span("request", cat="request", start_tick=arrival,
-                                end_tick=self._tick, lane="request", rid=rid)
-            if self._closed_loop:  # one completion releases one waiter
-                self._enqueue(self._closed_loop.popleft(), self._tick)
+        (often empty mid-pipeline).  The tick runs inside the ``serve/step``
+        program span."""
+        with self.spans.region("serve/step", cat="serve", lane="step",
+                               tick=self._tick):
+            t0 = time.perf_counter()
+            self._admit_arrivals()
+            if self.route == "cascade":
+                n_exec = len(self.pipeline.executed)
+                done = self._step_cascade()
+                busy = len(self.pipeline.executed) > n_exec
+            elif self.route == "lm":
+                done = self._step_lm()
+                busy = bool(done)
+            else:
+                done = self._step_pod()
+                busy = bool(done)
+            if busy:  # tick->wall-clock calibration sample (busy only)
+                self._busy_wall_s.observe(time.perf_counter() - t0)
+            self._completed += len(done)
+            self._completed_c.inc(len(done))
+            now = time.perf_counter()
+            for rid, _ in done:
+                if rid in self._arrival_tick:
+                    arrival = self._arrival_tick[rid]
+                    self._e2e_ticks.observe(self._tick - arrival)
+                    self.spans.span(
+                        "request", cat="request", start_tick=arrival,
+                        end_tick=self._tick, lane="request", rid=rid,
+                        start_s=self._submit_s.pop(rid, None), end_s=now)
+                if self._closed_loop:  # one completion releases one waiter
+                    self._enqueue(self._closed_loop.popleft(), self._tick)
         self._tick += 1
         self._pending_g.set(self.pending())
         if not self.pending():
@@ -612,7 +644,7 @@ class ServeEngine:
         """``stats["clock"]`` + wall-clock req/s and tail latencies derived
         from the tick clock (schema in ``docs/serving.md``)."""
         ts = self.tick_seconds()
-        self.stats["clock"] = {
+        self._stats["clock"] = {
             "tick_seconds": ts,
             "source": ("configured" if self.serve_cfg.tick_seconds is not None
                        else "calibrated"),
@@ -620,11 +652,11 @@ class ServeEngine:
             "busy_ticks": len(self._busy_wall_s),
         }
         lat_ticks = self._e2e_ticks.summary()
-        self.stats["request_latency_ticks"] = lat_ticks
-        self.stats["request_latency_s"] = {k: v * ts
+        self._stats["request_latency_ticks"] = lat_ticks
+        self._stats["request_latency_s"] = {k: v * ts
                                            for k, v in lat_ticks.items()}
         wall = self._tick * ts
-        self.stats["requests_per_s"] = (self._completed / wall) if wall else 0.0
+        self._stats["requests_per_s"] = (self._completed / wall) if wall else 0.0
 
     def pending(self) -> int:
         """Requests anywhere in the system: deferred arrivals, scheduler

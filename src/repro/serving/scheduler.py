@@ -30,7 +30,8 @@ class Request:
     prompt_len: int
     max_new_tokens: int = 0  # LM decode budget
     denoise_steps: int = 0  # diffusion requests
-    arrived_at: float = 0.0
+    arrived_at: float = 0.0  # arrival tick
+    arrived_s: float = 0.0  # arrival wall time (time.perf_counter())
     state: Any = None
 
 

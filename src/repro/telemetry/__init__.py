@@ -6,7 +6,10 @@ Three pillars (see ``docs/observability.md``):
   on the scheduler-tick clock — admission waits, per-stage queue/execute
   slices, park/resume/migrate/scale instants — collected per engine and
   exported as Chrome trace-event JSON
-  (:mod:`repro.telemetry.chrome_trace`, viewable in Perfetto).
+  (:mod:`repro.telemetry.chrome_trace`, viewable in Perfetto).  Program
+  spans (``serve/step``, ``serve/pod``, ``serve/stage/<name>``) are also
+  stamped on the wall clock and annotated into a profiler trace, and
+  :mod:`repro.telemetry.compiles` counts JAX compiles per stage under them.
 - **Metrics** (:mod:`repro.telemetry.metrics`): typed ``Counter`` /
   ``Gauge`` / ``Histogram`` registry; ``Histogram`` is the streaming
   fixed-bucket percentile estimator behind the engine's latency stats, and
@@ -38,7 +41,7 @@ from repro.telemetry.schema import (
     validate_fleet_summary,
     validate_snapshot,
 )
-from repro.telemetry.spans import SpanCollector, SpanEvent
+from repro.telemetry.spans import SpanCollector, SpanEvent, open_spans
 
 __all__ = [
     "Counter",
@@ -49,6 +52,7 @@ __all__ = [
     "json_ready",
     "SpanCollector",
     "SpanEvent",
+    "open_spans",
     "chrome_trace_events",
     "write_chrome_trace",
     "write_trace",

@@ -257,6 +257,12 @@ class MetricsRegistry:
     def histogram(self, name: str, help: str = "", **kwargs) -> Histogram:
         return self._get("histogram", name, lambda: Histogram(name, help, **kwargs))
 
+    def counters(self, prefix: str = "") -> dict[str, float]:
+        """Values of the counters whose names start with ``prefix``."""
+        return {name: metric.value
+                for name, (kind, metric) in self._metrics.items()
+                if kind == "counter" and name.startswith(prefix)}
+
     def snapshot(self) -> dict:
         from repro.telemetry.schema import SNAPSHOT_SCHEMA_VERSION
 

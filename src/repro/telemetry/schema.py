@@ -148,6 +148,8 @@ def validate_engine_stats(stats: dict, route: str) -> None:
             c.num(s, "exec_s", sp, minimum=0.0)
             c.num(s, "items", sp, minimum=0)
             c.num(s, "dispatches", sp, minimum=0)
+            c.num(s, "compiles", sp, minimum=0)
+            c.num(s, "compile_s", sp, minimum=0.0)
             for k in ("impl", "effective_impl"):
                 c.check(isinstance(s.get(k), str), f"{sp}.{k}: expected str")
     # clock + derived wall-clock stats (present once the engine drained)

@@ -294,14 +294,15 @@ class GenerativeWorkload:
 
     def generate(self, params, tokens, key, *, impl="auto",
                  max_new_tokens: int = 0, temperature: float = 0.0,
-                 rids=None, stage_impl: dict | None = None, on_stage=None,
+                 rids=None, stage_impl: dict | None = None, spans=None,
                  mesh=None):
         """Batched full-pipeline inference: (B, S) tokens -> stacked output.
 
         This is THE canonical stage composition: ``init_stage_state`` per
         request, then the descriptor's stage sequence through ``run_stage``
-        (each dispatch wrapped in a driver-emitted ``tracer.scope`` named
-        after the stage), then ``stage_output``.  The serving engine's pod
+        (each dispatch wrapped in :func:`repro.pipeline.stage.stage_span`:
+        a ``tracer.scope`` named after the stage and the
+        ``serve/stage/<name>`` program span), then ``stage_output``.  The serving engine's pod
         and lm routes and the cascade pipeline all execute this same
         machinery, so served outputs and ``trace_events`` characterization
         can never drift — and under the ``stage_key`` PRNG contract the
@@ -312,9 +313,10 @@ class GenerativeWorkload:
         by the batch (per-request budgets produce ragged outputs — use
         :meth:`generate_requests`, which returns a list); ``stage_impl``
         overrides the kernel tier per stage (exact name or prefix, same
-        semantics as ``ServeConfig.stage_impl``); ``on_stage(name, wall_s,
-        batch)`` is an optional per-dispatch callback the engine uses for
-        per-stage time attribution; ``mesh`` (optional ``jax.sharding.Mesh``
+        semantics as ``ServeConfig.stage_impl``); ``spans`` (optional
+        ``SpanCollector``) records the stage spans and, with its registry,
+        the per-stage counts behind the engine's ``stats["stages"]``;
+        ``mesh`` (optional ``jax.sharding.Mesh``
         with ``data``/``model`` axes) runs every stage data-parallel over
         the batch with TP-sharded params — outputs stay mesh-invariant
         under the PRNG contract (see ``parallel.mesh_exec``)."""
@@ -323,20 +325,17 @@ class GenerativeWorkload:
         return jnp.stack(self.generate_requests(
             params, tokens, key, impl=impl, max_new_tokens=max_new_tokens,
             temperature=temperature, rids=rids, stage_impl=stage_impl,
-            on_stage=on_stage, mesh=mesh))
+            spans=spans, mesh=mesh))
 
     def generate_requests(self, params, tokens, key, *, impl="auto",
                           max_new_tokens=0, temperature: float = 0.0,
                           rids=None, stage_impl: dict | None = None,
-                          on_stage=None, mesh=None) -> list:
+                          spans=None, mesh=None) -> list:
         """The :meth:`generate` driver, returning per-request outputs as a
         list (what the serving routes consume — per-request outputs may
         differ in length, so ``max_new_tokens`` may also be a per-request
         sequence here, e.g. heterogeneous LM decode budgets)."""
-        import time
-
-        from repro.core import tracer
-        from repro.pipeline.stage import split_state, stack_states
+        from repro.pipeline.stage import split_state, stack_states, stage_span
 
         stages, impls = self._stage_plan(impl, stage_impl)
         B = int(tokens.shape[0])
@@ -354,13 +353,10 @@ class GenerativeWorkload:
         mesh_kw = {} if mesh is None else {"mesh": mesh}
         for idx, stage in enumerate(stages):
             keys = stage_keys(key, rids, idx)
-            t0 = time.perf_counter()
-            with tracer.scope(stage.name):
+            with stage_span(spans, stage, batch=B, tier=impls[idx]):
                 state = self.run_stage(
                     params, stage, state, keys,
                     impl=impls[idx], temperature=temperature, **mesh_kw)
-            if on_stage is not None:
-                on_stage(stage.name, time.perf_counter() - t0, B)
         return [self.stage_output(s) for s in split_state(state, B)]
 
     def _stage_plan(self, impl: str, stage_impl: dict | None):

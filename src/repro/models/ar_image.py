@@ -148,7 +148,7 @@ class ARImageModel(Module):
         m = (labels >= 0).astype(jnp.float32)
         return jnp.sum((logz - ll) * m) / jnp.maximum(jnp.sum(m), 1.0)
 
-    # -- decode-loop primitives (driven ONLY by ARImageWorkload.run_stage) ---
+    # -- decode-loop primitives (driven ONLY by ARImageWorkload.stage_body) -
 
     def decode_parallel(self, params, ctx, *, impl="auto"):
         """Muse parallel decoding: iterative unmasking with a cosine schedule.

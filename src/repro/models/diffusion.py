@@ -53,9 +53,14 @@ def ddim_range(eps_fn, z, total_steps, start, stop):
     TTV keyframe denoise -> temporal refinement).  Under an active trace the
     single-step events are scaled by ``stop - start`` instead of tracing the
     loop (every step executes the identical graph).
+
+    The schedule (``alphas``, ``ts``) is computed eagerly on the device even
+    inside a jitted stage, and enters the program as constants: XLA never
+    folds it on the host, where its rounding could differ.
     """
-    alphas = ddpm_alphas()
-    ts = jnp.linspace(999, 0, total_steps).astype(jnp.int32)
+    with jax.ensure_compile_time_eval():
+        alphas = ddpm_alphas()
+        ts = jnp.linspace(999, 0, total_steps).astype(jnp.int32)
 
     if tracer.active():
         from repro.core.tracer import _traces

@@ -214,7 +214,7 @@ class MakeAVideoPipeline(Module):
                                t.astype(jnp.float32), ctx, impl=impl)
         return jnp.mean((pred.astype(jnp.float32) - eps) ** 2)
 
-    # Inference is driven ONLY by MakeAVideoWorkload.run_stage: the
+    # Inference is driven ONLY by MakeAVideoWorkload.stage_body: the
     # factorized keyframe (spatial-only) -> temporal-refinement sampler is
     # the one sampler definition on every serve route (there is no separate
     # joint-schedule pipeline driver anymore).

@@ -288,7 +288,9 @@ class ServeEngine:
         dispatch plus any trace, lowering and compile, and on the cascade
         route the wait for the result — not device time; ``compiles`` /
         ``compile_s`` are the JAX compiles charged to the stage
-        (``repro.telemetry.compiles``).  The cascade route's richer
+        (``repro.telemetry.compiles``); ``programs`` counts the stage's
+        compiled programs, one per (tier, mesh, batch shape), 0 for a stage
+        that runs eagerly.  The cascade route's richer
         per-stage report lives in ``stats["cascade"]``; the legacy lm keys
         (``prefill_s`` / ``decode_s``) mirror their stages' ``exec_s``."""
         s = self._stats
@@ -299,7 +301,8 @@ class ServeEngine:
                     stage_counts(c, name), impl=impl,
                     effective_impl=effective,
                     compiles=int(c.get(f"compiles/{name}", 0)),
-                    compile_s=c.get(f"compile_s/{name}", 0.0))
+                    compile_s=c.get(f"compile_s/{name}", 0.0),
+                    programs=int(c.get(f"stage_programs/{name}", 0)))
         for name, key in (("prefill", "prefill_s"), ("decode", "decode_s")):
             if key in s and name in s["stages"]:
                 s[key] = s["stages"][name]["exec_s"]

@@ -17,6 +17,10 @@ region's collector:
 ``<label>`` is the stage name inside a ``serve/stage/<name>`` span and
 ``other`` inside any other program span.  Events outside every program
 span belong to no engine and are not counted.
+
+:func:`count_program` keeps a third counter the same way,
+``stage_programs/<stage>``: one per trace of a stage body into a new
+compiled program (``GenerativeWorkload._compiled_stage``), a cache miss.
 """
 
 from __future__ import annotations
@@ -27,7 +31,7 @@ from jax import monitoring
 
 from repro.telemetry.spans import open_spans
 
-__all__ = ["COMPILE_EVENTS", "install", "stage_compiles"]
+__all__ = ["COMPILE_EVENTS", "count_program", "install", "stage_compiles"]
 
 COMPILE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
@@ -60,6 +64,15 @@ def _on_span(event: str, start: float, end: float, **_) -> None:
         _newly_covered(stack[0].covered, start, end))
     if event == _BACKEND:
         metrics.counter(f"compiles/{span.charge}").inc()
+
+
+def count_program(stage: str) -> None:
+    """Count one new compiled program of ``stage`` into the registry of the
+    innermost program span open on this thread (called while the stage's
+    body is traced, so it runs once per program, never on a cache hit)."""
+    stack = open_spans()
+    if stack and stack[-1].collector.metrics is not None:
+        stack[-1].collector.metrics.counter(f"stage_programs/{stage}").inc()
 
 
 def _newly_covered(covered: list, start: float, end: float) -> float:

@@ -150,6 +150,7 @@ def validate_engine_stats(stats: dict, route: str) -> None:
             c.num(s, "dispatches", sp, minimum=0)
             c.num(s, "compiles", sp, minimum=0)
             c.num(s, "compile_s", sp, minimum=0.0)
+            c.num(s, "programs", sp, minimum=0)
             for k in ("impl", "effective_impl"):
                 c.check(isinstance(s.get(k), str), f"{sp}.{k}: expected str")
     # clock + derived wall-clock stats (present once the engine drained)

@@ -66,15 +66,8 @@ class ARImageWorkload(GenerativeWorkload):
             ),
         )
 
-    def run_stage(self, params, stage, state, key, *, impl="auto",
-                  temperature: float = 0.0, mesh=None):
-        if mesh is not None:
-            from repro.parallel.mesh_exec import run_stage_on_mesh
-
-            return run_stage_on_mesh(self, params, stage, state, key,
-                                     impl=impl, temperature=temperature,
-                                     mesh=mesh)
-        del key, temperature  # greedy/confidence decode rules: deterministic
+    def stage_body(self, params, stage, state, key, *, impl):
+        del key  # greedy/confidence decode rules: deterministic
         model = self.model
         if stage.name == "text_encoder":
             ctx = model.text_encoder(params["text"], state["tokens"],

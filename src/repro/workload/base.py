@@ -92,7 +92,7 @@ def stage_key(key, rid: int, stage_index: int):
 def stage_keys(key, rids, stage_index: int):
     """Stacked ``(B, ...)`` per-request keys for one batched stage dispatch.
     ``run_stage`` implementations draw per-request noise by ``jax.vmap``-ing
-    over axis 0 (see ``DiffusionWorkload.run_stage``)."""
+    over axis 0 (see ``DiffusionWorkload.stage_body``)."""
     import jax.numpy as jnp
 
     return jnp.stack([stage_key(key, rid, stage_index) for rid in rids])
@@ -231,6 +231,7 @@ class GenerativeWorkload:
                 f"workload route (expected one of {WORKLOAD_ROUTES})")
         self.cfg = cfg
         self.model = self.build_model(cfg)
+        self._stage_programs: dict = {}  # (stage, impl, mesh) -> jitted body
 
     # -- construction --------------------------------------------------------
 
@@ -423,10 +424,60 @@ class GenerativeWorkload:
         ``impl`` selects the kernel tier *for this stage* (the drivers
         resolve per-stage overrides before calling); ``temperature`` is the
         sampling temperature for token-sampling stages (0 = greedy) —
-        workloads whose samplers don't take a temperature ignore it."""
+        workloads whose samplers don't take a temperature ignore it.
+
+        This default is the eager entry of a workload whose stages are
+        traceable (they read no value on the host): it runs
+        :meth:`stage_body` as one jitted program, built once per (stage,
+        tier, ambient mesh) on this instance and keyed by XLA on shapes, so
+        a later pod of the same shape traces, lowers and compiles nothing.
+        Drivers call this entry, so wrappers placed on it see concrete
+        states.  While the characterization tracer is active the body runs
+        eagerly: it records operator events as Python side effects of
+        tracing, which a compiled-program cache hit would skip.  Workloads
+        that read values on the host (LM decode) override ``run_stage``."""
+        if mesh is not None:
+            from repro.parallel.mesh_exec import run_stage_on_mesh
+
+            return run_stage_on_mesh(self, params, stage, state, key,
+                                     impl=impl, temperature=temperature,
+                                     mesh=mesh)
+        from repro.core import tracer
+
+        del temperature  # no traceable stage takes a temperature
+        if tracer.active():
+            return self.stage_body(params, stage, state, key, impl=impl)
+        return self._compiled_stage(stage, impl)(params, state, key)
+
+    def stage_body(self, params, stage: Stage, state: dict, key, *,
+                   impl: str) -> dict:
+        """The traceable body of :meth:`run_stage`: ``(params, state, key)
+        -> state`` for one ``stage`` on kernel tier ``impl``."""
         raise NotImplementedError(
-            f"{type(self).__name__} does not implement run_stage "
-            f"(stage {stage.name!r})")
+            f"{type(self).__name__} implements neither run_stage nor "
+            f"stage_body (stage {stage.name!r})")
+
+    def _compiled_stage(self, stage: Stage, impl: str):
+        """The jitted ``(params, state, key) -> state`` program of ``stage``
+        on tier ``impl`` under the ambient mesh.  ``params`` stay an
+        argument (closing over them would bake the weights into the program
+        as constants); no buffer is donated, since callers keep each stage's
+        input state.  Each trace of the body — a new program — counts one
+        ``stage_programs/<stage>`` in the open span's registry."""
+        import jax
+
+        from repro.parallel.sharding import ambient_mesh
+        from repro.telemetry.compiles import count_program
+
+        cache_key = (stage, impl, ambient_mesh())
+        fn = self._stage_programs.get(cache_key)
+        if fn is None:
+            def body(params, state, key):
+                count_program(stage.name)
+                return self.stage_body(params, stage, state, key, impl=impl)
+
+            fn = self._stage_programs[cache_key] = jax.jit(body)
+        return fn
 
     def stage_group_key(self, stage: Stage, state: dict):
         """Extra batch-compatibility key for ``stage`` over an unbatched

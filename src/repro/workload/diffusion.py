@@ -96,17 +96,9 @@ class DiffusionWorkload(GenerativeWorkload):
         return CostDescriptor(arch=cfg.name, route=self.route,
                               stages=tuple(stages))
 
-    def run_stage(self, params, stage, state, key, *, impl="auto",
-                  temperature: float = 0.0, mesh=None):
+    def stage_body(self, params, stage, state, key, *, impl):
         import jax
 
-        if mesh is not None:
-            from repro.parallel.mesh_exec import run_stage_on_mesh
-
-            return run_stage_on_mesh(self, params, stage, state, key,
-                                     impl=impl, temperature=temperature,
-                                     mesh=mesh)
-        del temperature  # DDIM sampling has no temperature knob
         model, cfg = self.model, self.cfg
         if stage.name == "text_encoder":
             ctx = model.encode_text(params, state["tokens"], impl=impl)

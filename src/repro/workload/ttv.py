@@ -80,20 +80,11 @@ class MakeAVideoWorkload(GenerativeWorkload):
         return CostDescriptor(arch=cfg.name, route=self.route,
                               stages=tuple(stages))
 
-    def run_stage(self, params, stage, state, key, *, impl="auto",
-                  temperature: float = 0.0, mesh=None):
+    def stage_body(self, params, stage, state, key, *, impl):
         import jax
         import jax.numpy as jnp
 
         from repro.models.diffusion import ddim_range
-
-        if mesh is not None:
-            from repro.parallel.mesh_exec import run_stage_on_mesh
-
-            return run_stage_on_mesh(self, params, stage, state, key,
-                                     impl=impl, temperature=temperature,
-                                     mesh=mesh)
-        del temperature  # DDIM sampling has no temperature knob
 
         model, cfg = self.model, self.cfg
 
@@ -168,15 +159,8 @@ class PhenakiWorkload(GenerativeWorkload):
             ),
         )
 
-    def run_stage(self, params, stage, state, key, *, impl="auto",
-                  temperature: float = 0.0, mesh=None):
-        if mesh is not None:
-            from repro.parallel.mesh_exec import run_stage_on_mesh
-
-            return run_stage_on_mesh(self, params, stage, state, key,
-                                     impl=impl, temperature=temperature,
-                                     mesh=mesh)
-        del key, temperature  # confidence-based unmasking: deterministic
+    def stage_body(self, params, stage, state, key, *, impl):
+        del key  # confidence-based unmasking: deterministic
         model = self.model
         if stage.name == "text_encoder":
             ctx = model.text_encoder(params["text"], state["tokens"],
